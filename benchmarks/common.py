@@ -48,9 +48,15 @@ def timed(fn, *args, repeats=3, **kw):
 
 
 def run_subprocess(code: str, devices: int = 0, timeout: int = 2400) -> str:
-    import os
+    """Run ``code`` in a child Python on the CPU and return its stdout.
+
+    Children are always pinned to ``JAX_PLATFORMS=cpu`` (``devices`` > 0
+    gives them that many host devices): the parent harness may already
+    hold the accelerator, and a chip belongs to one process.  Rows built
+    from children are CPU rows; on-chip checks live in ``chip_smoke.py``.
+    """
     env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+           "JAX_PLATFORMS": "cpu"}
     if devices:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

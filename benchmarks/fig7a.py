@@ -13,11 +13,9 @@ the loop: at bits=2 the built-in exact identity ONN resolves without
 training, so ``--fidelity mesh`` runs the fast Givens-layer emulator
 (repro.photonics.mesh) inside every jitted step and must reproduce the
 behavioral losses EXACTLY (same RNG, bit-exact collective) — the loop
-below ASSERTS that equality.  On TPU a third ``optinc_b2_mesh_pallas``
-row runs the fused kernel (``--mesh-backend pallas``) under the same
-equality gate; off-TPU the kernel interprets (far too slow for
-gradient-sized batches — tests/test_photonics.py carries the
-multi-device pallas bit-exactness gate there instead).
+below ASSERTS that equality.  Every row runs in a CPU child
+(``common.run_subprocess``); the compiled fused mesh kernel is checked
+against the XLA executor by ``chip_smoke.py``'s mesh-kernel phase.
 
 ``--smoke`` (CI) runs only the short behavioral LM rows.
 """
@@ -85,21 +83,6 @@ def main(full: bool = False, smoke: bool = False):
         flush_json("fig7a")
 
 
-def _tpu_children() -> bool:
-    """Will the LM_RUN subprocesses run on TPU?  Probed in a subprocess
-    with the SAME env run_subprocess gives the training rows (importing
-    jax here would take the TPU lock and break every child on exactly
-    the platform the probe exists to detect).  Note run_subprocess pins
-    children to cpu when JAX_PLATFORMS is unset, so on a TPU VM the
-    pallas row requires an explicit JAX_PLATFORMS=tpu — matching where
-    the children actually run, never the parent's hardware."""
-    try:
-        out = run_subprocess("import jax; print(jax.default_backend())")
-        return out.strip().splitlines()[-1] == "tpu"
-    except Exception:
-        return False
-
-
 def _run(full: bool, smoke: bool):
     lm_steps = 60 if full else (6 if smoke else 25)
     rn_steps = 30 if full else 10
@@ -113,12 +96,6 @@ def _run(full: bool, smoke: bool):
                  ("optinc_b2_behavioral", "optinc", ', "--bits", "2"'),
                  ("optinc_b2_mesh", "optinc",
                   ', "--bits", "2", "--fidelity", "mesh"')]
-        if _tpu_children():
-            # interpret-mode pallas is minutes/step at gradient batch
-            # sizes; the fused-kernel row only makes sense compiled
-            runs.append(("optinc_b2_mesh_pallas", "optinc",
-                         ', "--bits", "2", "--fidelity", "mesh", '
-                         '"--mesh-backend", "pallas"'))
     losses = {}
     for name, sync, extra in runs:
         out = run_subprocess(LM_RUN.format(sync=sync, steps=lm_steps,
@@ -128,19 +105,18 @@ def _run(full: bool, smoke: bool):
         emit(f"fig7a.llama.{name}", 0.0,
              f"loss_first={rec['first']:.4f} loss_last={rec['last']:.4f} "
              f"steps={lm_steps}")
-    # the advertised hardware-in-the-loop equality is a gate, not prose.
-    # Exactness holds for the pallas row too, even compiled: at bits=2 /
-    # N=1 the exact-identity ONN's analog outputs are small integers
-    # represented exactly in f32, so no readout sits near a PAM4 decision
-    # boundary where executor rounding could flip it (the trained-B=8
-    # harness, whose readouts DO approach boundaries, budgets tolerance
-    # instead — benchmarks/trained_onn.py).
+    # the advertised hardware-in-the-loop equality is a gate, not prose:
+    # at bits=2 / N=1 the exact-identity ONN's analog outputs are small
+    # integers represented exactly in f32, so no readout sits near a PAM4
+    # decision boundary where executor rounding could flip it (the
+    # trained-B=8 harness, whose readouts DO approach boundaries, budgets
+    # tolerance instead — benchmarks/trained_onn.py).
     beh = losses.get("optinc_b2_behavioral")
-    for name in ("optinc_b2_mesh", "optinc_b2_mesh_pallas"):
-        if beh is not None and name in losses and losses[name] != beh:
-            raise RuntimeError(
-                f"{name} losses {losses[name]} diverged from behavioral "
-                f"{beh} — the fidelity cascade is no longer bit-exact")
+    mesh_row = losses.get("optinc_b2_mesh")
+    if beh is not None and mesh_row is not None and mesh_row != beh:
+        raise RuntimeError(
+            f"optinc_b2_mesh losses {mesh_row} diverged from behavioral "
+            f"{beh} — the fidelity cascade is no longer bit-exact")
     if smoke:
         return
     for name, sync, err in [("baseline_psum", "psum", "()"),

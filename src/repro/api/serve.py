@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import compat  # noqa: F401  (jax API shims)
 from ..models import lm
 from ..serving import reload as serving_reload
 from . import build

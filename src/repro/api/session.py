@@ -18,7 +18,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from .. import compat  # noqa: F401  (jax API shims: set_mesh et al.)
 from ..checkpoint import CheckpointManager, load_checkpoint
 from ..checkpoint.ckpt import latest_step, read_manifest
 from ..collectives import (is_packed_residuals, pack_residuals,

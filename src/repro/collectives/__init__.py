@@ -10,8 +10,6 @@ Layout:
 ``repro.core.collective`` re-exports this surface for backwards
 compatibility with the pre-refactor import path.
 """
-from .. import compat  # noqa: F401  (installs jax API shims first)
-
 from .backends import (CascadeBackend, OptincBackend, PsumBackend,
                        RingBackend, _ring_allreduce_flat)
 from .bucketizer import (DEFAULT_BUCKET_BYTES, BucketLayout, bucketize,

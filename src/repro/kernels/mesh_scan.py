@@ -35,13 +35,13 @@ column and an optional diagonal epilogue (the Sigma_a ``d`` scale of
 the whole ``diag(post) . G_1^T..G_K^T . diag(pre)`` chain is one kernel.
 
 PhaseNoise theta drift is drawn IN-KERNEL: with ``theta_std > 0`` each
-block's grid step derives a (L, m) standard-normal field from a per-block
-uint32 seed (folded off the step key by the caller) via a counter-based
-splitmix32 hash + Box-Muller — no perturbed (ca, sa) stacks are ever
-materialized in XLA, and the same portable uint32 arithmetic runs
-compiled and interpreted.  ``theta_std == 0`` traces NONE of the noise
-code (no seed operand, no extra ops), so the zero-noise kernel stays
-bit-exact with the noise-free parity rows.  Shot noise (additive, on
+layer of a block's grid step derives its row of an (L, m) standard-normal
+field from a per-block uint32 seed (folded off the step key by the
+caller) via a counter-based splitmix32 hash + Box-Muller — no perturbed
+(ca, sa) stacks are ever materialized in XLA, and the same portable
+uint32 arithmetic runs compiled and interpreted.  ``theta_std == 0``
+traces NONE of the noise code (no seed operand, no extra ops), so the
+zero-noise kernel stays bit-exact with the noise-free parity rows.  Shot noise (additive, on
 the output) stays an XLA epilogue in ``photonics.mesh``.
 
 VMEM budget (f32, the compiled-TPU case): the layer stacks cost
@@ -86,23 +86,33 @@ def _mix32(x):
     return x ^ (x >> jnp.uint32(16))
 
 
-def _normal_field(seed, n_layers: int, m: int, dt):
-    """(L, m) standard normals from one uint32 seed, counter-based.
+def _uniform24(h, dt):
+    """The top 24 bits of a uint32 word as a float in [0, 2^24).  Goes
+    through int32 (exact: the value is < 2^24) because Mosaic has no
+    uint32 -> float cast."""
+    return (h >> jnp.uint32(8)).astype(jnp.int32).astype(dt)
+
+
+def _normal_row(seed, row, m: int, dt):
+    """(1, m) standard normals of layer ``row`` from one uint32 seed,
+    counter-based.
 
     Two independent uint32 hash streams per (layer, wire) counter feed a
     Box-Muller transform.  Plain jnp uint32 arithmetic — identical bits
     compiled and interpreted, unlike ``pltpu.prng_random_bits`` (which
-    has no CPU interpreter lowering on this jax), so CPU CI can
-    statistically validate the same draws the TPU makes.
+    has no lowering in the Pallas interpreter), so CPU CI can
+    statistically validate the same draws the TPU makes.  The kernel
+    draws each layer's row inside its layer loop: Mosaic cannot slice a
+    precomputed (L, m) field at a traced layer index.
     """
-    row = jax.lax.broadcasted_iota(jnp.uint32, (n_layers, m), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (n_layers, m), 1)
-    base = (row * jnp.uint32(m) + col) * jnp.uint32(0x9E3779B9) + seed
+    col = jax.lax.broadcasted_iota(jnp.uint32, (1, m), 1)
+    base = ((row.astype(jnp.uint32) * jnp.uint32(m) + col)
+            * jnp.uint32(0x9E3779B9) + seed)
     h1 = _mix32(base)
     h2 = _mix32(base ^ jnp.uint32(0x85EBCA6B))
     # 24-bit mantissa uniforms; u1 in (0, 1] keeps the log finite
-    u1 = ((h1 >> jnp.uint32(8)).astype(dt) + 1.0) * jnp.asarray(2.0 ** -24, dt)
-    u2 = (h2 >> jnp.uint32(8)).astype(dt) * jnp.asarray(2.0 ** -24, dt)
+    u1 = (_uniform24(h1, dt) + 1.0) * jnp.asarray(2.0 ** -24, dt)
+    u2 = _uniform24(h2, dt) * jnp.asarray(2.0 ** -24, dt)
     r = jnp.sqrt(jnp.asarray(-2.0, dt) * jnp.log(u1))
     return r * jnp.cos(jnp.asarray(2.0 * jnp.pi, dt) * u2)
 
@@ -127,7 +137,7 @@ def _mesh_scan_blocks_kernel(*refs, n_layers: int, transpose: bool,
 
     dt = y_ref.dtype
     m = pre_ref.shape[-1]
-    y = (x_ref[0] if x_blocked else x_ref[...]) * pre_ref[...]
+    y = (x_ref[0] if x_blocked else x_ref[...]) * pre_ref[0]
     # wire[i, j] = i; comparing against a perm row makes the one-hot
     # permutation matrix P with P[i, j] = (perm[j] == i), so y @ P is
     # y[..., perm] (TPU needs >= 2-D iota)
@@ -145,13 +155,11 @@ def _mesh_scan_blocks_kernel(*refs, n_layers: int, transpose: bool,
                 return carry
             jax.lax.fori_loop(0, n_layers, build, 0)
 
-    g = None
     if theta_std > 0.0:
         # one drift field per BLOCK and apply — identical across the
         # block's batch tiles (one physical mesh per block), varying only
         # with the per-block seed the caller folded off the step key
-        g = _normal_field(seed_ref[0, 0].astype(jnp.uint32),
-                          n_layers, m, dt)
+        seed = seed_ref[0].astype(jnp.uint32)                 # (1, 1)
 
     def body(i, y):
         l = (n_layers - 1 - i) if transpose else i
@@ -171,7 +179,7 @@ def _mesh_scan_blocks_kernel(*refs, n_layers: int, transpose: bool,
             # one-hot matmul IS g[perm]), antisymmetric sign ->
             # coherent theta -> theta + eps on both wires of each MZI;
             # untouched wires (perm == self) get sign 0, eps 0 exactly
-            g_row = jax.lax.dynamic_slice(g, (l, 0), (1, m))
+            g_row = _normal_row(seed, l, m, dt)
             g_p = jnp.dot(g_row, onehot, preferred_element_type=dt,
                           precision=jax.lax.Precision.HIGHEST)
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
@@ -186,7 +194,7 @@ def _mesh_scan_blocks_kernel(*refs, n_layers: int, transpose: bool,
         return ca * y - sa * y_p if transpose else ca * y + sa * y_p
 
     y = jax.lax.fori_loop(0, n_layers, body, y)
-    y_ref[...] = (y * post_ref[...]).astype(dt)[None]
+    y_ref[...] = (y * post_ref[0]).astype(dt)[None]
 
 
 # ------------------------------- dispatchers --------------------------------
@@ -268,10 +276,13 @@ def mesh_scan_blocks(signs: jnp.ndarray, perm: jnp.ndarray, ca: jnp.ndarray,
     oh_bytes = n_layers * m_pad * m_pad * jnp.dtype(dt).itemsize
     cache_onehot = n_tiles > 1 and oh_bytes <= ONEHOT_CACHE_BYTES
 
+    # per-block rows ride as (B, 1, m_pad) so every block's last two dims
+    # equal the array's (Mosaic's (8, 128) tiling rule)
     stack_spec = pl.BlockSpec((1, n_layers, m_pad), lambda i, j: (i, 0, 0))
-    col_spec = pl.BlockSpec((1, m_pad), lambda i, j: (i, 0))
+    col_spec = pl.BlockSpec((1, 1, m_pad), lambda i, j: (i, 0, 0))
     in_specs = [stack_spec, stack_spec, stack_spec, col_spec, col_spec]
-    operands = [perm, ca.astype(dt), sa.astype(dt), pre, post]
+    operands = [perm, ca.astype(dt), sa.astype(dt), pre[:, None],
+                post[:, None]]
     if x_block_axis:
         in_specs.append(pl.BlockSpec((1, blk_b, m_pad),
                                      lambda i, j: (i, j, 0)))
@@ -279,9 +290,9 @@ def mesh_scan_blocks(signs: jnp.ndarray, perm: jnp.ndarray, ca: jnp.ndarray,
         in_specs.append(pl.BlockSpec((blk_b, m_pad), lambda i, j: (j, 0)))
     operands.append(y)
     if theta_std > 0.0:
-        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)))
         operands.append(seeds.astype(jnp.uint32).astype(jnp.int32)
-                        .reshape(n_blocks, 1))
+                        .reshape(n_blocks, 1, 1))
 
     out = pl.pallas_call(
         functools.partial(_mesh_scan_blocks_kernel, n_layers=n_layers,

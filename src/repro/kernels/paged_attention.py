@@ -86,8 +86,11 @@ def _paged_attention_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0]                                    # (rep, hd) f32 scaled
     k = k_ref[0, 0].astype(jnp.float32)                # (page, hd)
     v = v_ref[0, 0].astype(jnp.float32)
+    # HIGHEST: at default precision the MXU rounds f32 operands to bf16,
+    # which put the compiled kernel ~1e-3 off the f32 gather oracle
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (rep, page)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)  # (rep, page)
     pos = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
     s = jnp.where(pos < len_ref[i], s, NEG_INF)
@@ -98,7 +101,8 @@ def _paged_attention_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     m_ref[...] = m_cur
     l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(j == n_blocks - 1)
     def _finalize():

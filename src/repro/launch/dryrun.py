@@ -29,7 +29,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat  # noqa: F401  (jax API shims; after XLA_FLAGS)
 from repro import configs
 from repro.api import MeshSpec, RunSpec, SpecError, SyncConfig, build
 from repro.api.shapes import (batch_sds, cache_sds, globalize_cache_sds,
@@ -121,8 +120,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, sync_mode: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     colls = roofline.parse_collectives(hlo)
     chips = mesh.devices.size

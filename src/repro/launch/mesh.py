@@ -7,23 +7,24 @@ cross-pod data-parallel axis whose gradient synchronization OptINC targets
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before any jax import).
-jax-version differences (AxisType, jax.shard_map, jax.set_mesh) are
-absorbed by repro.compat.
 """
 from __future__ import annotations
 
-from .. import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (smoke tests use (1, 1) or (2, 2))."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh (smoke tests use (1, 1) or (2, 2)), every axis
+    ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_sizes(mesh) -> dict:

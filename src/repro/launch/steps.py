@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat  # noqa: F401  (jax API shims)
 from ..collectives import SyncConfig, residual_size, sync_gradients
 from ..models import lm
 from ..models.config import ModelConfig

@@ -69,9 +69,11 @@ from __future__ import annotations
 import sys
 
 from repro.api import RunSpec, SpecError, TrainSession
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     try:
         spec = RunSpec.from_args(argv, description=__doc__)
         if spec.elastic.enabled:

@@ -240,7 +240,10 @@ def decode_attention(ctx: ShardCtx, q: jnp.ndarray, k_cache: jnp.ndarray,
     scale = hd ** -0.5
     qf = q.astype(jnp.float32).reshape(b, hkv, rep, hd) * scale
     kf = k_cache.astype(jnp.float32)
-    s = jnp.einsum("bgrd,bgkd->bgrk", qf, kf)
+    # f32 on every backend (TPU's default precision rounds f32 operands
+    # to bf16): the paged kernel computes the same f32 attention
+    hi = lax.Precision.HIGHEST
+    s = jnp.einsum("bgrd,bgkd->bgrk", qf, kf, precision=hi)
     if ctx.seq_shard_cache:
         offset = lax.axis_index(ctx.data_axis) * s_local
     else:
@@ -257,7 +260,8 @@ def decode_attention(ctx: ShardCtx, q: jnp.ndarray, k_cache: jnp.ndarray,
         m = lax.pmax(m, ctx.data_axis)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bgrk,bgkd->bgrd", p, v_cache.astype(jnp.float32))
+    acc = jnp.einsum("bgrk,bgkd->bgrd", p, v_cache.astype(jnp.float32),
+                     precision=hi)
     if ctx.seq_shard_cache:
         l = lax.psum(l, ctx.data_axis)
         acc = lax.psum(acc, ctx.data_axis)

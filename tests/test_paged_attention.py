@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat  # noqa: F401  (jax.shard_map shim on 0.4.x)
 from repro.kernels import paged_attention as pk
 from repro.models.layers import ShardCtx, decode_attention, paged_gather
 
