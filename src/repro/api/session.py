@@ -17,6 +17,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..checkpoint import CheckpointManager, load_checkpoint
 from ..checkpoint.ckpt import latest_step, read_manifest
@@ -187,16 +188,30 @@ class TrainSession:
 
     # ------------------------------------------------------------ the loop
     def run_step(self, step: int) -> dict:
-        """Execute one training step (caller holds the mesh context)."""
-        t0 = time.time()
-        batch = {"tokens": jnp.asarray(self.data.batch(step))}
-        key = jax.random.fold_in(self._base_key, step)
-        (self.params, self.opt_state, self.sync_state,
-         metrics) = self._jitted(self.params, self.opt_state,
-                                 self.sync_state, batch, key)
-        loss = float(metrics["loss"])
+        """Execute one training step (caller holds the mesh context).
+
+        The host phases are profiler spans -- ``train.step`` around
+        ``train.input`` (host batch and its copy to the device),
+        ``train.dispatch`` (the step's key and the enqueue of the jitted
+        step) and ``train.fetch`` (waiting for the loss) -- and the
+        record's seconds, taken at the same boundaries."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("train.step", step=step):
+            with TraceAnnotation("train.input"):
+                batch = {"tokens": jnp.asarray(self.data.batch(step))}
+            t1 = time.perf_counter()
+            with TraceAnnotation("train.dispatch"):
+                key = jax.random.fold_in(self._base_key, step)
+                (self.params, self.opt_state, self.sync_state,
+                 metrics) = self._jitted(self.params, self.opt_state,
+                                         self.sync_state, batch, key)
+            t2 = time.perf_counter()
+            with TraceAnnotation("train.fetch"):
+                loss = float(metrics["loss"])
+        t3 = time.perf_counter()
         return {"step": step, "loss": round(loss, 5),
-                "time_s": round(time.time() - t0, 3)}
+                "time_s": round(t3 - t0, 4), "input_s": round(t1 - t0, 4),
+                "dispatch_s": round(t2 - t1, 4), "fetch_s": round(t3 - t2, 4)}
 
     def run(self, n_steps: int | None = None) -> list:
         """Run to ``spec.steps`` (or ``n_steps`` more), firing callbacks.

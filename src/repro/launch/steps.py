@@ -222,15 +222,20 @@ def make_train_step(cfg: ModelConfig, mesh, sync: SyncConfig,
     bspec = batch_specs(ctx, cfg)
     sspec = sync_state_specs(mesh, sync)
 
+    # named scopes put each device op down to a phase in a profile; the
+    # backward's ops carry ``transpose(jvp(forward))`` in their op_name
     def step(params, opt_state, sync_state, batch, key):
         def lf(p):
-            return lm.loss_fn(cfg, ctx, p, batch)
+            with jax.named_scope("forward"):
+                return lm.loss_fn(cfg, ctx, p, batch)
         (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        grads, sync_state = _split_sync(grads, fsdp_mask, ctx, sync, key,
-                                        sync_state)
-        grads, gnorm = clip_by_global_norm(
-            grads, opt.clip_norm, axis_names=(ctx.model_axis,))
-        params, opt_state = adamw_update(opt, params, grads, opt_state)
+        with jax.named_scope("grad_sync"):
+            grads, sync_state = _split_sync(grads, fsdp_mask, ctx, sync, key,
+                                            sync_state)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(
+                grads, opt.clip_norm, axis_names=(ctx.model_axis,))
+            params, opt_state = adamw_update(opt, params, grads, opt_state)
         metrics = {"loss": lax.pmean(loss, ctx.dp_axes),
                    "grad_norm": gnorm}
         return params, opt_state, sync_state, metrics

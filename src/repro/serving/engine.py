@@ -38,6 +38,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from ..models import lm
@@ -125,18 +126,35 @@ class ServeEngine:
     def step(self):
         """Advance every active sequence by one token.  Returns the list
         of (rid, token) pairs emitted this step (prefill first-tokens of
-        newly admitted sequences included)."""
+        newly admitted sequences included).
+
+        Each host phase is a profiler span inside ``serve.step``, which
+        carries the active, queued and preempted-so-far counts as the
+        step starts: ``serve.reload``, ``serve.admit``, ``serve.prefill``,
+        ``serve.grow``, ``serve.pack`` (page table, lengths and tokens to
+        the device), ``serve.decode`` (the enqueue of the decode step),
+        ``serve.sample`` and ``serve.emit``."""
+        sched = self.sched
+        with TraceAnnotation("serve.step", active=len(sched.active),
+                             queued=len(sched.queue),
+                             preempted=sched.n_preempted):
+            return self._step()
+
+    def _step(self):
         self.step_count += 1
         if (self.reloader is not None
                 and self.step_count % self.scfg.reload_every == 0):
-            swapped = self.reloader.poll()
+            with TraceAnnotation("serve.reload"):
+                swapped = self.reloader.poll()
             if swapped is not None:
                 self.params, self.params_step = swapped
                 print(f"hot-swapped params to checkpoint step "
                       f"{self.params_step}", flush=True)
         emitted = []
         with jax.set_mesh(self.mesh):
-            admitted = self.sched.admit()
+            with TraceAnnotation("serve.admit") as span:
+                admitted = self.sched.admit()
+                span.set_metadata(admitted=len(admitted))
             if admitted:
                 emitted += self._prefill_batch(admitted)
             self._ensure_growth()
@@ -144,22 +162,25 @@ class ServeEngine:
             self.max_observed_active = max(self.max_observed_active, len(act))
             if not act:
                 return emitted
-            b = min(max(1, 1 << (len(act) - 1).bit_length()),
-                    self.scfg.max_active)
-            pt = np.zeros((b, self.scfg.max_blocks), np.int32)
-            ln = np.zeros((b,), np.int32)
-            tok = np.zeros((b, 1), np.int32)
-            for i, seq in enumerate(act):
-                pt[i, :len(seq.pages)] = seq.pages
-                ln[i] = seq.length
-                tok[i, 0] = seq.last_token
-            logits, self.pool = self._decode(
-                self.params, self.pool, jnp.asarray(pt), jnp.asarray(ln),
-                jnp.asarray(tok))
+            with TraceAnnotation("serve.pack"):
+                b = min(max(1, 1 << (len(act) - 1).bit_length()),
+                        self.scfg.max_active)
+                pt = np.zeros((b, self.scfg.max_blocks), np.int32)
+                ln = np.zeros((b,), np.int32)
+                tok = np.zeros((b, 1), np.int32)
+                for i, seq in enumerate(act):
+                    pt[i, :len(seq.pages)] = seq.pages
+                    ln[i] = seq.length
+                    tok[i, 0] = seq.last_token
+                pt, ln, tok = map(jnp.asarray, (pt, ln, tok))
+            with TraceAnnotation("serve.decode"):
+                logits, self.pool = self._decode(self.params, self.pool,
+                                                 pt, ln, tok)
             toks = self._sample(logits[:len(act)], act)
-        for seq, t in zip(list(act), toks):
-            seq.length += 1
-            emitted += self._push_token(seq, int(t))
+        with TraceAnnotation("serve.emit"):
+            for seq, t in zip(list(act), toks):
+                seq.length += 1
+                emitted += self._push_token(seq, int(t))
         return emitted
 
     def _ensure_growth(self):
@@ -167,15 +188,16 @@ class ServeEngine:
         when the pool runs dry the youngest sequences are preempted
         (pages freed, request re-queued with its generated tokens) until
         the remaining ones fit."""
-        i = 0
-        while i < len(self.sched.active):
-            seq = self.sched.active[i]
-            if self.sched.grow(seq):
-                i += 1
-                continue
-            victim = self.sched.preempt_youngest()
-            if victim is seq:  # even alone it can't grow — re-queued
-                break
+        with TraceAnnotation("serve.grow"):
+            i = 0
+            while i < len(self.sched.active):
+                seq = self.sched.active[i]
+                if self.sched.grow(seq):
+                    i += 1
+                    continue
+                victim = self.sched.preempt_youngest()
+                if victim is seq:  # even alone it can't grow — re-queued
+                    break
 
     def _len_bucket(self, t: int) -> int:
         """Prompt-length bucket: pow2 rounded up to a whole number of
@@ -200,24 +222,27 @@ class ServeEngine:
         into its own pages and its first token sampled from its own
         last-position logits.  Pad rows carry length 0: write_prompts
         drops their KV and their logits are discarded."""
-        feeds = [s.req.prompt + s.req.generated for s in seqs]
-        n = len(feeds)
-        tb = self._len_bucket(max(len(f) for f in feeds))
-        bb = self._row_bucket(n)
-        tok = np.zeros((bb, tb), np.int32)
-        ln = np.zeros((bb,), np.int32)
-        pt = np.zeros((bb, tb // self.scfg.page_size), np.int32)
-        for i, (seq, feed) in enumerate(zip(seqs, feeds)):
-            tok[i, :len(feed)] = feed
-            ln[i] = len(feed)
-            pt[i, :len(seq.pages)] = seq.pages
-        ln = jnp.asarray(ln)
-        logits, pkv = self._prefill(self.params, jnp.asarray(tok), ln)
-        self.pool = self._write_prompts(self.pool, pkv, jnp.asarray(pt), ln)
-        emitted = []
-        for seq, t in zip(seqs, self._sample(logits[:n], seqs)):
-            emitted += self._push_token(seq, int(t))
-        return emitted
+        with TraceAnnotation("serve.prefill") as span:
+            feeds = [s.req.prompt + s.req.generated for s in seqs]
+            n = len(feeds)
+            tb = self._len_bucket(max(len(f) for f in feeds))
+            bb = self._row_bucket(n)
+            tok = np.zeros((bb, tb), np.int32)
+            ln = np.zeros((bb,), np.int32)
+            pt = np.zeros((bb, tb // self.scfg.page_size), np.int32)
+            for i, (seq, feed) in enumerate(zip(seqs, feeds)):
+                tok[i, :len(feed)] = feed
+                ln[i] = len(feed)
+                pt[i, :len(seq.pages)] = seq.pages
+            span.set_metadata(rows=n, tokens=int(ln.sum()), padded=bb * tb)
+            ln = jnp.asarray(ln)
+            logits, pkv = self._prefill(self.params, jnp.asarray(tok), ln)
+            self.pool = self._write_prompts(self.pool, pkv,
+                                            jnp.asarray(pt), ln)
+            emitted = []
+            for seq, t in zip(seqs, self._sample(logits[:n], seqs)):
+                emitted += self._push_token(seq, int(t))
+            return emitted
 
     def _push_token(self, seq: Sequence, tok: int):
         seq.req.generated.append(tok)
@@ -235,23 +260,24 @@ class ServeEngine:
 
     # -------------------------------------------------------------- sample
     def _sample(self, logits, seqs):
-        logits = logits[:, :self.cfg.vocab]
-        if self.scfg.temperature == 0.0:
-            return np.asarray(jnp.argmax(logits, axis=-1))
-        out = []
-        for row, seq in zip(logits, seqs):
-            # per-(request, position) key: deterministic under preemption
-            # and re-batching
-            key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(self.spec.seed),
-                                   seq.req.rid),
-                len(seq.req.generated))
-            row = row / self.scfg.temperature
-            if self.scfg.top_k:
-                kth = jnp.sort(row)[-self.scfg.top_k]
-                row = jnp.where(row < kth, -jnp.inf, row)
-            out.append(int(jax.random.categorical(key, row)))
-        return np.asarray(out)
+        with TraceAnnotation("serve.sample"):
+            logits = logits[:, :self.cfg.vocab]
+            if self.scfg.temperature == 0.0:
+                return np.asarray(jnp.argmax(logits, axis=-1))
+            out = []
+            for row, seq in zip(logits, seqs):
+                # per-(request, position) key: deterministic under preemption
+                # and re-batching
+                key = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.PRNGKey(self.spec.seed),
+                                       seq.req.rid),
+                    len(seq.req.generated))
+                row = row / self.scfg.temperature
+                if self.scfg.top_k:
+                    kth = jnp.sort(row)[-self.scfg.top_k]
+                    row = jnp.where(row < kth, -jnp.inf, row)
+                out.append(int(jax.random.categorical(key, row)))
+            return np.asarray(out)
 
     # --------------------------------------------------------------- drive
     def serve(self, prompts, max_new_tokens=None) -> dict:
