@@ -1,95 +1,90 @@
-"""Pallas TPU flash-attention kernel (online softmax over KV tiles).
+"""Fused flash attention for training and prefill (Pallas TPU, forward and
+backward).
 
-The generic perf-critical layer of the model zoo: prefill attention at 32k
-sequence cannot materialize (sq, skv) scores in HBM. We tile Q into
-(BLK_Q, d) blocks resident in VMEM, stream K/V tiles, and keep the running
-max / normalizer / output accumulator in VMEM scratch — O(sq * d) memory.
+A wrapper over the splash attention kernels that ship with JAX
+(``jax.experimental.pallas.ops.tpu.splash_attention``): one forward
+kernel (online softmax over KV tiles, the score tile never leaves VMEM)
+and two backward kernels (dq; dk and dv), joined by a custom VJP.  GQA
+is the multi-head kernel over ``hkv`` KV heads, each serving ``h / hkv``
+query heads, vmapped over the batch: K and V are never repeated.
 
-Single-head kernel; ops.py vmaps over (batch, heads) and handles GQA
-broadcasting. Causal masking is computed from program ids, and fully-masked
-KV tiles are skipped via the grid (no wasted MXU work past the diagonal).
+Causal masking is splash's ``CausalMask`` with the ``skv - sq`` offset
+(row r sees columns ``<= r + skv - sq``, as ``blocked_attention``); the
+mask's block info skips every tile wholly above the diagonal in the
+forward and in both backward kernels.
+
+Precision: the model's operands go in as they are (bf16 in every
+configuration that trains), with the ``1/sqrt(hd)`` scale applied to q
+and rounded back to q's dtype, which is what XLA's DEFAULT precision
+does to ``blocked_attention``'s f32 operands on TPU.  Products
+accumulate in f32; the running max, normaliser and logsumexp, and the
+dk / dv / dq accumulators are f32; the output and the gradients go back
+to the operands' dtype.
+
+``supports`` says which shapes the kernel takes; ``use_kernel`` where
+it runs (compiled on TPU).  ``kernels.ops.flash_attention`` dispatches
+between this and ``models.layers.blocked_attention`` from both.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-NEG_INF = -1e30
+from ..photonics.config import resolve_interpret
+
+BLOCKS = (512, 256, 128)   # tile edges tried, largest first
+
+# test hook: True forces the (interpreted, off-TPU) kernel into the
+# model's dispatch, False forces blocked_attention, None = platform
+FORCE_KERNEL: bool | None = None
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, causal: bool, blk_q: int, blk_k: int,
-                  kv_steps: int, sq: int, skv: int):
-    qi = pl.program_id(0)
-    ki = pl.program_id(1)
+def use_kernel() -> bool:
+    """Should the model's attention run this kernel?  Compiled on TPU;
+    elsewhere ``blocked_attention`` (interpret mode is a test vehicle).
+    The module-level ``FORCE_KERNEL`` test hook wins."""
+    if FORCE_KERNEL is not None:
+        return bool(FORCE_KERNEL)
+    return jax.default_backend() == "tpu"
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal: rows attend to kv positions <= row + (skv - sq)
-    @pl.when((ki * blk_k <= qi * blk_q + blk_q - 1 + (skv - sq))
-             if causal else (ki >= 0))
-    def _step():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows + (skv - sq), s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+def block(n: int) -> int | None:
+    """The tile edge for a sequence of ``n``: the largest of ``BLOCKS``
+    that divides it, or None when none does."""
+    return next((b for b in BLOCKS if n % b == 0), None)
 
-    @pl.when(ki == kv_steps - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+def supports(q_shape, k_shape, v_shape) -> bool:
+    """Can the kernel take q (b, h, sq, hd), k (b, hkv, skv, hd),
+    v (b, hkv, skv, hdv)?  Equal q/k and v head dims, a multiple of the
+    128 lanes, and both lengths a multiple of a tile."""
+    hd, hdv = q_shape[-1], v_shape[-1]
+    return (hd == hdv and hd % 128 == 0 and block(q_shape[2]) is not None
+            and block(k_shape[2]) is not None)
+
+
+def _kernel(h: int, sq: int, skv: int, causal: bool, interpret: bool):
+    bq, bkv = block(sq), block(skv)
+    one = (splash.CausalMask((sq, skv), offset=skv - sq) if causal
+           else splash.FullMask((sq, skv)))
+    sizes = splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        block_q_dq=bq, block_kv_dq=bkv)
+    return splash.make_splash_mha(
+        splash.MultiHeadMask([one] * h), block_sizes=sizes, head_shards=1,
+        q_seq_shards=1, interpret=interpret)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    causal: bool = True, scale: float | None = None,
-                    blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
-    """Single-head attention. q: (sq, d), k/v: (skv, d)."""
-    sq, d = q.shape
-    skv = k.shape[0]
-    if scale is None:
-        scale = d ** -0.5
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, skv)
-    assert sq % blk_q == 0 and skv % blk_k == 0
-    kv_steps = skv // blk_k
-    grid = (sq // blk_q, kv_steps)
-    return pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k, kv_steps=kv_steps,
-                          sq=sq, skv=skv),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk_q, d), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((blk_k, d), lambda qi, ki: (ki, 0)),
-            pl.BlockSpec((blk_k, d), lambda qi, ki: (ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((blk_q, d), lambda qi, ki: (qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+                    causal: bool = True,
+                    interpret: bool | None = None) -> jnp.ndarray:
+    """GQA attention, differentiable.  q: (b, h, sq, hd), k/v:
+    (b, hkv, skv, hd) of q's dtype, with ``supports`` true and, where
+    causal, sq <= skv.  Returns (b, h, sq, hd) in q's dtype."""
+    b, h, sq, hd = q.shape
+    skv = k.shape[2]
+    kernel = _kernel(h, sq, skv, causal, resolve_interpret(interpret))
+    qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
+    return jax.vmap(kernel)(qs, k, v)

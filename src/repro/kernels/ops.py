@@ -1,21 +1,22 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
 Dispatch policy: on TPU backends the Pallas kernels run compiled
-(interpret=False); on CPU (this container) the *model code* uses the pure
-jnp references so dry-runs lower to ordinary HLO, while tests run the
-Pallas kernel bodies in interpret mode against the references.
+(interpret=False); on CPU the *model code* uses the pure jnp paths so
+dry-runs lower to ordinary HLO, while tests run the Pallas kernel bodies
+in interpret mode against the references.
 """
 from __future__ import annotations
 
+import collections
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from . import pam4 as pam4_k
 from . import onn_layer as onn_k
 from . import attention as attn_k
+from ..models.layers import blocked_attention
 
 
 def _on_tpu() -> bool:
@@ -51,16 +52,19 @@ def onn_layer(x, u, d, b, relu: bool = True):
 
 # ---------------------------- attention -----------------------------
 
-@partial(jax.jit, static_argnames=("causal",))
+# trace-time count of the model's attention dispatch, keyed by path
+ATTENTION_PATHS: collections.Counter = collections.Counter()
+
+
 def flash_attention(q, k, v, causal: bool = True):
-    """Multi-head GQA attention. q: (b, hq, sq, d), k/v: (b, hkv, skv, d)."""
-    b, hq, sq, d = q.shape
-    hkv = k.shape[1]
-    rep = hq // hkv
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    if _on_tpu():
-        f = partial(attn_k.flash_attention, causal=causal, interpret=False)
+    """Multi-head GQA attention for training and prefill. q: (b, hq, sq, d),
+    k/v: (b, hkv, skv, d[v]).  The fused kernel (``kernels.attention``)
+    where it runs and takes the shapes; ``blocked_attention`` elsewhere
+    (CPU, MLA's V head dim, lengths off the kernel's tiles)."""
+    if attn_k.use_kernel() and attn_k.supports(q.shape, k.shape, v.shape):
+        path, fn = "attn_fused", attn_k.flash_attention
     else:
-        f = partial(ref.mha_ref, causal=causal)
-    return jax.vmap(jax.vmap(f))(q, k, v)
+        path, fn = "attn_blocked", blocked_attention
+    ATTENTION_PATHS[path] += 1
+    with jax.named_scope(path):
+        return fn(q, k, v, causal=causal)

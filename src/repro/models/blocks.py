@@ -15,8 +15,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels import ops as kernel_ops
 from .config import ModelConfig
-from .layers import (NEG_INF, ShardCtx, blocked_attention, decode_attention,
+from .layers import (NEG_INF, ShardCtx, decode_attention,
                      embed_lookup, gather_fsdp, paged_gather,
                      paged_update_cache, rmsnorm, rope, sp_gather, sp_out,
                      swiglu_mlp, update_cache)
@@ -93,7 +94,7 @@ def gqa_attention(ctx: ShardCtx, cfg: ModelConfig, p, x, pos,
             k = k.transpose(0, 2, 1, 3)
             v = v.transpose(0, 2, 1, 3)
             new_cache = {"k": k, "v": v}   # collected by prefill, DCE'd in train
-        attn = blocked_attention(q, k, v, causal=causal)
+        attn = kernel_ops.flash_attention(q, k, v, causal=causal)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, hl * cfg.hd)
     out = attn @ gather_fsdp(ctx, p["wo"], 1)
     return sp_out(ctx, out), new_cache
@@ -163,9 +164,9 @@ def mla_attention(ctx: ShardCtx, cfg: ModelConfig, p, x, pos,
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, t, hl, rd))],
                             axis=-1)
         qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-        attn = blocked_attention(qf.transpose(0, 2, 1, 3),
-                                 k.transpose(0, 2, 1, 3),
-                                 v.transpose(0, 2, 1, 3), causal=True)
+        attn = kernel_ops.flash_attention(qf.transpose(0, 2, 1, 3),
+                                          k.transpose(0, 2, 1, 3),
+                                          v.transpose(0, 2, 1, 3))
         attn = attn.transpose(0, 2, 1, 3).reshape(b, t, hl * hd)
         out = sp_out(ctx, attn @ gather_fsdp(ctx, p["wo"], 1))
         # quantized compressed cache, collected by prefill (DCE'd in train)

@@ -161,8 +161,10 @@ NEG_INF = -1e30
 def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       causal: bool = True, blk_q: int = 1024,
                       blk_kv: int = 512) -> jnp.ndarray:
-    """Online-softmax attention, scan over Q tiles x KV tiles (jnp twin of
-    the Pallas flash kernel; O(blk_q*blk_kv) score memory).
+    """Online-softmax attention, scan over Q tiles x KV tiles
+    (O(blk_q*blk_kv) score memory).  The CPU path, the path for shapes the
+    fused kernel does not take (MLA's V head dim, lengths off its tiles;
+    ``kernels.ops.flash_attention`` dispatches), and the kernel's oracle.
 
     q: (b, h, sq, hd), k/v: (b, hkv, skv, hd). GQA-aware."""
     b, h, sq, hd = q.shape

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.attention import flash_attention
 from repro.kernels.mesh_scan import mesh_scan_blocks
 from repro.kernels.paged_attention import paged_attention
 
@@ -97,3 +98,25 @@ def test_paged_attention_compiles_for_v5e(one_chip, pool_dtype, rep, hd):
                         sds((n_pages, hkv, page, hd), pool_dtype),
                         sds((b, nb), jnp.int32), sds((b,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("b,h,hkv,s,grad", [
+    (2, 24, 8, 4096, True), (4, 56, 8, 1024, False)],
+    ids=["minitron_train", "dscoder_prefill"])
+def test_flash_attention_compiles_for_v5e(one_chip, b, h, hkv, s, grad):
+    """The fused attention at the training cell's shape, forward and
+    backward (the forward kernel and both backward kernels), and at a
+    serving prefill's, forward only; bf16 operands, causal."""
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    hlo = compiled_text(fn, sds((b, h, s, 128)), sds((b, hkv, s, 128)),
+                        sds((b, hkv, s, 128)))
+    assert hlo.count("tpu_custom_call") >= (3 if grad else 1)
