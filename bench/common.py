@@ -9,6 +9,8 @@ that ``BENCHMARK.json`` gives it:
 * ``bench/configs/<config>.json``  model configuration as it is run
 * ``bench/traffic/<traffic>.json`` traffic mix (parameters only)
 * ``bench/metrics/<metric>.py``    per-layer metric reader
+* ``bench/families/<family>.py``  model family, named by the config's
+  ``"family"``
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import json
 import math
 import os
 import pathlib
+import re
 import sys
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
@@ -80,6 +83,30 @@ def load_reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family(model_cfg: dict):
+    """The module ``bench/families/<family>.py`` of the family that the
+    configuration names, loaded once per process (its jitted functions
+    and ``Dims`` class stay one object)."""
+    name = model_cfg.get("family")
+    path = BENCH_DIR / "families" / f"{name}.py"
+    if not (isinstance(name, str) and path.is_file()
+            and re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_-]*", name)):
+        known = sorted(p.stem for p in path.parent.glob("[!_]*.py"))
+        raise BenchError(f"configuration {model_cfg.get('name')!r} names "
+                         f"no known model family ({name!r}; known: {known})")
+    mod_name = f"bench.families.{name}"
+    if mod_name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[mod_name]
+            raise
+    return sys.modules[mod_name]
 
 
 def process_age_s() -> float:

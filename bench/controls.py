@@ -37,28 +37,30 @@ from . import common, reference
 
 def train_readings(model_cfg, mix, chips, seed) -> dict:
     from . import train_cell as tc
-    dims = reference.Dims.from_config(model_cfg)
+    fam = common.family(model_cfg)
+    dims = fam.Dims.from_config(model_cfg)
     opt = reference.AdamW.from_config(model_cfg["optimizer"])
     rows = tc.batches(dims, mix, chips, seed)
     ex = tc.exchange_of(mix)
-    ref = tc.ref_readings(dims, opt, rows, seed, chips=chips, ex=ex)
+    ref = tc.ref_readings(fam, dims, opt, rows, seed, chips=chips, ex=ex)
     out = {}
-    ctl = tc.ref_readings(dims, opt, rows, seed, reference.FP8, chips, ex)
+    ctl = tc.ref_readings(fam, dims, opt, rows, seed, reference.FP8, chips,
+                          ex)
     out["control_fp8"] = tc.gaps_of(*ctl, *ref)
     half = [r[: max(1, len(r) // 2)] for r in rows]
-    h = tc.ref_readings(dims, opt, half, seed, chips=chips, ex=ex)
+    h = tc.ref_readings(fam, dims, opt, half, seed, chips=chips, ex=ex)
     out["half_batch"] = tc.gaps_of(*h, *ref)
     if chips > 1:
-        solo = tc.ref_readings(dims, opt, rows, seed, chips=chips,
+        solo = tc.ref_readings(fam, dims, opt, rows, seed, chips=chips,
                                ex=reference.Exchange("none"))
         out["no_exchange"] = tc.gaps_of(*solo, *ref)
     return out
 
 
-def fp8_first_gap(dims, w, prompt, served, seq_len, max_out) -> float:
+def fp8_first_gap(fam, dims, w, prompt, served, seq_len, max_out) -> float:
     from . import serve_cell as sc
-    ref = sc.ref_logits(dims, w, prompt, served, seq_len, max_out)
-    low = sc.ref_logits(dims, w, prompt, served, seq_len, max_out,
+    ref = sc.ref_logits(fam, dims, w, prompt, served, seq_len, max_out)
+    low = sc.ref_logits(fam, dims, w, prompt, served, seq_len, max_out,
                         ar=reference.FP8)
     return reference.served_gap(ref, np.argmax(low, axis=-1))
 
@@ -66,8 +68,9 @@ def fp8_first_gap(dims, w, prompt, served, seq_len, max_out) -> float:
 def serve_readings(model_cfg, mix, seed, seconds, devs) -> dict:
     import jax
     from . import serve_cell as sc, traffic as gen
-    dims = reference.Dims.from_config(model_cfg)
-    engine = sc.build_engine(dims, model_cfg, mix, seed)
+    fam = common.family(model_cfg)
+    dims = fam.Dims.from_config(model_cfg)
+    engine = sc.build_engine(fam, dims, model_cfg, mix, seed)
     sc.warm_up(engine, mix)
     schedule = gen.serve_schedule(mix, seed, seconds,
                                   seconds + mix["drain_s"] + 10, dims.vocab)
@@ -79,10 +82,10 @@ def serve_readings(model_cfg, mix, seed, seconds, devs) -> dict:
     gc.collect()
     for a in jax.live_arrays():
         a.delete()
-    prog = sc.check(dims, mix, seed, sample)["served_gap"]
-    w = reference.init_weights(dims, seed)
+    prog = sc.check(fam, dims, mix, seed, sample)["served_gap"]
+    w = fam.init_weights(dims, seed)
     seq_len, max_out = mix["serve"]["max_seq"], mix["output"]["max"]
-    gap = max(fp8_first_gap(dims, w, p, s, seq_len, max_out)
+    gap = max(fp8_first_gap(fam, dims, w, p, s, seq_len, max_out)
               for p, s in sample)
     del w
     return {"program": prog,

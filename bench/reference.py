@@ -1,21 +1,15 @@
-"""Plain reference of the dense GQA decoder that the cells run.
+"""Plain reference of what the cells run, in the parts that do not depend
+on the model: matrix-product arithmetic, synthetic tokens, the norms,
+rotary and attention that the families' layers share, AdamW, the
+data-parallel exchange, and the served-token gap.  Each model family's
+layers, weights and loss are in ``bench/families/<family>.py``.
 
 Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no
 kernels, no cache, no batching tricks, no sharding.  It imports nothing
-of the program under test; weights and tokens are remade here from the
-seed with the same recipes the program documents (``init_weights``,
-``synthetic_tokens``), so the comparison never reads a value the program
-computed.
-
-Layer equations (pre-norm decoder, as in ``BENCHMARK.json``'s configs):
-
-    h  = rmsnorm(x) * g_attn
-    q, k, v = h Wq, h Wk, h Wv ; rotary on q, k (rotate-half, full head)
-    a  = softmax(q k^T / sqrt(hd) + causal) v   (GQA: head i reads kv i//rep)
-    x  = x + a Wo
-    h  = rmsnorm(x) * g_mlp
-    x  = x + (silu(h Wg) * (h Wu)) Wd
-    logits = rmsnorm(x) * g_final  Whead
+of the program under test; weights and tokens are remade from the seed
+with the same recipes the program documents (a family's
+``init_weights``, ``synthetic_tokens``), so the comparison never reads a
+value the program computed.
 
 ``Arith`` selects how matrix products round their operands: ``f32`` is
 the reference; ``fp8`` rounds both operands of every product to
@@ -34,29 +28,6 @@ from jax import lax
 
 HI = lax.Precision.HIGHEST
 F8_MAX = 448.0            # largest finite float8_e4m3fn
-
-
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    n_layers: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-
-    @classmethod
-    def from_config(cls, c: dict) -> "Dims":
-        return cls(d_model=c["hidden_size"],
-                   n_heads=c["num_attention_heads"],
-                   n_kv_heads=c["num_key_value_heads"],
-                   head_dim=c["head_dim"], d_ff=c["intermediate_size"],
-                   n_layers=c["num_hidden_layers"], vocab=c["vocab_size"],
-                   rope_theta=float(c["rope_theta"]),
-                   norm_eps=float(c["rms_norm_eps"]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,52 +52,6 @@ class Arith:
 
 F32 = Arith("f32")
 FP8 = Arith("fp8")
-
-
-# ------------------------------------------------------------ weights
-def weight_shapes(dm: Dims) -> dict:
-    """The stacked-layer layout: every layer's leaf carries a leading
-    layer axis.  Flattened in sorted-key order, as JAX flattens dicts."""
-    d, L, hd = dm.d_model, dm.n_layers, dm.head_dim
-    return {
-        "embed": (dm.vocab, d),
-        "final_norm": (d,),
-        "layers": {
-            "mlp_norm": (L, d), "norm": (L, d),
-            "w_down": (L, dm.d_ff, d), "w_gate": (L, d, dm.d_ff),
-            "w_up": (L, d, dm.d_ff),
-            "wk": (L, d, dm.n_kv_heads * hd), "wo": (L, dm.n_heads * hd, d),
-            "wq": (L, d, dm.n_heads * hd), "wv": (L, d, dm.n_kv_heads * hd),
-        },
-        "lm_head": (d, dm.vocab),
-    }
-
-
-def init_weights(dm: Dims, seed: int):
-    """Seeded bf16 weights in one call on the device: one key per leaf
-    (``split`` of the seed's key in flattened order), matrices
-    N(0, 0.02^2), norm scales 1."""
-    return _init_weights(dm, jax.random.PRNGKey(seed))
-
-
-@partial(jax.jit, static_argnums=(0,))
-def _init_weights(dm: Dims, key):
-    shapes = weight_shapes(dm)
-    leaves, treedef = jax.tree.flatten(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))
-    paths = [jax.tree_util.keystr(p) for p, _ in
-             jax.tree_util.tree_flatten_with_path(
-                 shapes, is_leaf=lambda x: isinstance(x, tuple))[0]]
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, shp, path in zip(keys, leaves, paths):
-        if len(shp) == 1 or path.endswith("norm']"):
-            out.append(jnp.ones(shp, jnp.bfloat16))
-        else:
-            scale = 0.02 if shp[-2] > 8 else 0.5
-            out.append((jax.random.normal(k, shp, jnp.float32) * scale
-                        ).astype(jnp.bfloat16))
-    return jax.tree.unflatten(treedef, out)
 
 
 # ------------------------------------------------------------- tokens
@@ -192,55 +117,7 @@ def attention(q, k, v, ar: Arith, chunk: int = 512):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, H * hd)
 
 
-def layer(dm: Dims, ar: Arith, x, p):
-    b, t, _ = x.shape
-    hd = dm.head_dim
-    h = rmsnorm(x, p["norm"], dm.norm_eps)
-    q = ar.mm(h, p["wq"]).reshape(b, t, dm.n_heads, hd)
-    k = ar.mm(h, p["wk"]).reshape(b, t, dm.n_kv_heads, hd)
-    v = ar.mm(h, p["wv"]).reshape(b, t, dm.n_kv_heads, hd)
-    a = attention(rope(q, dm.rope_theta), rope(k, dm.rope_theta), v, ar)
-    x = x + ar.mm(a, p["wo"])
-    h = rmsnorm(x, p["mlp_norm"], dm.norm_eps)
-    g = jax.nn.silu(ar.mm(h, p["w_gate"]))
-    return x + ar.mm(g * ar.mm(h, p["w_up"]), p["w_down"])
-
-
-def hidden(dm: Dims, ar: Arith, w, tokens, remat: bool):
-    """Final-normed hidden states (b, t, d) in f32."""
-    # f32 before the gather, so repeated tokens' gradients add in f32
-    x = w["embed"].astype(jnp.float32)[tokens]
-
-    def body(x, p):
-        return layer(dm, ar, x, p), None
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = lax.scan(body, x, w["layers"])
-    return rmsnorm(x, w["final_norm"], dm.norm_eps)
-
-
 # ------------------------------------------------------------ training
-def loss(dm: Dims, ar: Arith, w, tokens, chunk: int = 1024):
-    """Mean next-token NLL over every position of every row."""
-    h = hidden(dm, ar, w, tokens[:, :-1], remat=True)
-    tgt = tokens[:, 1:]
-    b, t, d = h.shape
-    c = min(chunk, t)
-    hs = jnp.moveaxis(h.reshape(b, t // c, c, d), 1, 0)
-    ts = jnp.moveaxis(tgt.reshape(b, t // c, c), 1, 0)
-    head = w["lm_head"].astype(jnp.float32)   # chunk gradients add in f32
-
-    @jax.checkpoint
-    def nll(args):
-        hc, tc = args
-        logits = ar.mm(hc, head)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        return jnp.sum(lse - jnp.take_along_axis(logits, tc[..., None],
-                                                 -1)[..., 0])
-
-    return jnp.sum(lax.map(nll, (hs, ts))) / (b * t)
-
-
 @dataclasses.dataclass(frozen=True)
 class AdamW:
     lr: float = 3e-4
@@ -256,8 +133,8 @@ class AdamW:
                       for f in dataclasses.fields(cls)})
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _loss_and_grad(dm, ar, w, tokens):
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _loss_and_grad(loss, dm, ar, w, tokens):
     return jax.value_and_grad(lambda p: loss(dm, ar, p, tokens))(w)
 
 
@@ -374,11 +251,12 @@ def exchange(grads: list, ex: Exchange, dev):
     return jax.tree.unflatten(treedef, new)
 
 
-def train(dm: Dims, opt: AdamW, w, batches, ar: Arith = F32, chips: int = 1,
+def train(loss, dm, opt: AdamW, w, batches, ar: Arith = F32, chips: int = 1,
           ex: Exchange = MEAN) -> dict:
-    """Runs len(batches) AdamW steps from weights ``w`` (consumed), with
-    each step's rows split evenly over ``chips`` data-parallel chips,
-    each chip's mean-loss gradient taken alone and the chips' gradients
+    """Runs len(batches) AdamW steps of a family's ``loss(dm, ar, w,
+    tokens)`` from weights ``w`` (consumed), with each step's rows split
+    evenly over ``chips`` data-parallel chips, each chip's mean-loss
+    gradient taken alone and the chips' gradients
     made one by ``ex``.  The chips' gradients are computed on as many
     devices as there are, round robin.  Returns the loss of each step
     (the mean over chips), the per-leaf norms of the first clipped
@@ -392,7 +270,7 @@ def train(dm: Dims, opt: AdamW, w, batches, ar: Arith = F32, chips: int = 1,
         for c, r in enumerate(rows):
             d = devs[c % len(devs)]
             wc = w if d == dev else jax.device_put(w, d)
-            lval, g = _loss_and_grad(dm, ar, wc, jax.device_put(r, d))
+            lval, g = _loss_and_grad(loss, dm, ar, wc, jax.device_put(r, d))
             per_chip.append(g)
             lvals.append(lval)
             del wc
@@ -411,13 +289,6 @@ def train(dm: Dims, opt: AdamW, w, batches, ar: Arith = F32, chips: int = 1,
 
 
 # ------------------------------------------------------------- serving
-@partial(jax.jit, static_argnums=(0, 1))
-def logits_at(dm: Dims, ar: Arith, w, tokens, positions):
-    """f32 logits (n, vocab) at ``positions`` of one sequence (1, t)."""
-    h = hidden(dm, ar, w, tokens, remat=False)[0]
-    return ar.mm(h[positions], w["lm_head"])
-
-
 def served_gap(ref_logits, tokens) -> float:
     """Widest gap by which a served token's reference logit lies below
     the reference's best at that position."""

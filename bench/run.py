@@ -12,7 +12,8 @@ ends by comparing what the timed path produced with the plain reference
 is inside its limit.
 
 Exits 2, printing no result, when JAX finds no TPU or fewer chips than
-the cell asks for, or when a file the cell names is missing.
+the cell asks for, or when a file the cell names is missing, the model
+family its configuration names among them.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ def main(argv=None) -> int:
         if not (common.SRC / "repro").is_dir():
             raise common.BenchError(f"no program under {common.SRC}")
         sys.path.insert(0, str(common.SRC))
+        common.family(model_cfg)
         devs = common.require_chips(cell["chips"])
     except (common.BenchError, OSError, KeyError) as e:
         print(f"bench: {e}", file=sys.stderr)

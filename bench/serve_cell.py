@@ -32,18 +32,12 @@ import numpy as np
 from . import common, reference, traffic as gen
 
 
-def build_engine(dims, model_cfg: dict, mix: dict, seed: int):
+def build_engine(fam, dims, model_cfg: dict, mix: dict, seed: int):
     from repro.api.spec import MeshSpec, RunSpec
-    from repro.models.config import ModelConfig
     from repro.serving.config import ServeConfig
     from repro.serving.engine import ServeEngine
 
-    mcfg = ModelConfig(
-        name=model_cfg["name"], family="dense", n_layers=dims.n_layers,
-        d_model=dims.d_model, n_heads=dims.n_heads,
-        n_kv_heads=dims.n_kv_heads, d_ff=dims.d_ff, vocab=dims.vocab,
-        head_dim=dims.head_dim, rope_theta=dims.rope_theta,
-        dtype=model_cfg["dtype"])
+    mcfg = fam.program_config(dims, model_cfg)
 
     class CellSpec(RunSpec):
         def model_config(self):
@@ -52,7 +46,7 @@ def build_engine(dims, model_cfg: dict, mix: dict, seed: int):
     s = mix["serve"]
     spec = CellSpec(arch=model_cfg["name"], mesh=MeshSpec(dp=1), seed=seed,
                     serve=ServeConfig(**s))
-    params = reference.init_weights(dims, seed)
+    params = fam.init_weights(dims, seed)
     return ServeEngine(spec, params=params)
 
 
@@ -213,7 +207,7 @@ def pick_sample(engine, log: Log, seed: int, want_tokens: int,
     return [(sched[k].prompt, list(map(int, toks))) for k, toks in out]
 
 
-def ref_logits(dims, w, prompt, served, seq_len: int, max_out: int,
+def ref_logits(fam, dims, w, prompt, served, seq_len: int, max_out: int,
                ar=reference.F32):
     """Reference logits at each served token's position: the model reads
     prompt + served[:-1], padded to ``seq_len`` (causal, so the padding
@@ -225,20 +219,19 @@ def ref_logits(dims, w, prompt, served, seq_len: int, max_out: int,
     pos = len(prompt) - 1 + np.arange(len(served))
     pos_p = np.full((max_out,), pos[-1], np.int32)
     pos_p[:len(pos)] = pos
-    out = reference.logits_at(dims, ar, w, jnp.asarray(tok),
-                              jnp.asarray(pos_p))
+    out = fam.logits_at(dims, ar, w, jnp.asarray(tok), jnp.asarray(pos_p))
     return np.asarray(out)[:len(served)]
 
 
-def check(dims, mix: dict, seed: int, sample: list) -> dict:
+def check(fam, dims, mix: dict, seed: int, sample: list) -> dict:
     """The widest gap by which a served token's reference logit lies
     below the reference's best, over every position of the sample."""
-    w = reference.init_weights(dims, seed)
+    w = fam.init_weights(dims, seed)
     gap = 0.0
     seq_len = mix["serve"]["max_seq"]
     max_out = mix["output"]["max"]
     for prompt, served in sample:
-        lg = ref_logits(dims, w, prompt, served, seq_len, max_out)
+        lg = ref_logits(fam, dims, w, prompt, served, seq_len, max_out)
         gap = max(gap, reference.served_gap(lg, served))
     del w
     served_n = sum(len(s) for _, s in sample)
@@ -251,9 +244,10 @@ def run(cell: dict, model_cfg: dict, mix: dict, seed: int, seconds: float,
         devs, counter, tracer=None) -> tuple:
     import jax
 
-    dims = reference.Dims.from_config(model_cfg)
+    fam = common.family(model_cfg)
+    dims = fam.Dims.from_config(model_cfg)
     seed = seed % 2 ** 31
-    engine = build_engine(dims, model_cfg, mix, seed)
+    engine = build_engine(fam, dims, model_cfg, mix, seed)
     warm_up(engine, mix)
     schedule = gen.serve_schedule(mix, seed, seconds,
                                   seconds + mix["drain_s"] + 10.0, dims.vocab)
@@ -288,11 +282,11 @@ def run(cell: dict, model_cfg: dict, mix: dict, seed: int, seconds: float,
         a.delete()
 
     t = time.perf_counter()
-    c = check(dims, mix, seed, sample)
+    c = check(fam, dims, mix, seed, sample)
     check_s = time.perf_counter() - t
     sampled = c.pop("sampled_tokens")
     c["window_compiles"] = common.check(counter.count, 0)
-    readings = {"window_s": t_end, "dims": dims,
+    readings = {"window_s": t_end, "family": fam, "dims": dims,
                 "device_kind": device["kind"], "chips": cell["chips"],
                 "kv_bytes": 2, "n_layers": dims.n_layers}
     result = {

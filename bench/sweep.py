@@ -20,7 +20,7 @@ import argparse
 import json
 import sys
 
-from . import common, reference
+from . import common
 
 
 def main(argv=None) -> int:
@@ -39,8 +39,9 @@ def main(argv=None) -> int:
     common.enable_compile_cache()
     from . import serve_cell as sc, traffic as gen
     seed = args.seed % 2 ** 31
-    dims = reference.Dims.from_config(model_cfg)
-    engine = sc.build_engine(dims, model_cfg, mix, seed)
+    fam = common.family(model_cfg)
+    dims = fam.Dims.from_config(model_cfg)
+    engine = sc.build_engine(fam, dims, model_cfg, mix, seed)
     sc.warm_up(engine, mix)
     orders = [int(o) for o in args.orders.split(",") if o] or \
         [mix["schedule_seed"]]

@@ -6,7 +6,7 @@ import pathlib
 
 import pytest
 
-from bench import common, phases, reference, trace
+from bench import common, phases, trace
 
 DATA = pathlib.Path(__file__).parent / "data"
 
@@ -160,9 +160,10 @@ def test_program_spans_and_scopes_leave_the_readers_alone(metric):
         (n, s, e) for n, s, e, _ in program], program_spans=program,
         scopes={n: "jit(step)/jvp(forward)/x" for d in
                 base["devices"].values() for n, _, _ in d["ops"]})
-    dims = reference.Dims.from_config(
-        common.config_file("deepseek-coder-33b-stage8"))
-    r = {"lo": lo, "hi": hi, "steps": 3, "chips": 1, "dims": dims,
+    cfg = common.config_file("deepseek-coder-33b-stage8")
+    fam = common.family(cfg)
+    r = {"lo": lo, "hi": hi, "steps": 3, "chips": 1, "family": fam,
+         "dims": fam.Dims.from_config(cfg),
          "tokens_per_step": 8192, "seq_len": 4096,
          "device_kind": "TPU v5 lite", "kv_bytes": 2,
          "calls": {"bench.decode": [[100, 200, 300, 400]] * 3,

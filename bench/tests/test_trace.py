@@ -61,13 +61,14 @@ def test_op_seconds_and_modules():
 def test_train_readers_on_the_small_trace():
     """step_mfu.train divides by the trace's busy time per step, and the
     exposed collective time is per step."""
-    from bench import common, flops, reference
-    dims = reference.Dims.from_config(
-        common.config_file("minitron-4b-stage4"))
+    from bench import common
+    cfg = common.config_file("minitron-4b-stage4")
+    fam = common.family(cfg)
+    dims = fam.Dims.from_config(cfg)
     r = {"trace": TR, "lo": 0, "hi": 40, "steps": 2, "chips": 1,
-         "tokens_per_step": 8192, "seq_len": 4096, "dims": dims,
-         "device_kind": "TPU v5 lite"}
-    per_step = flops.train_flops_per_token(dims, 4096) * 8192
+         "tokens_per_step": 8192, "seq_len": 4096, "family": fam,
+         "dims": dims, "device_kind": "TPU v5 lite"}
+    per_step = fam.train_flops_per_token(dims, 4096) * 8192
     mfu = common.load_reader("step_mfu.train")(r)
     assert mfu == pytest.approx(100 * per_step / (15e-9 * 197e12))
     exposed = common.load_reader("collective_exposed_ms.train")(r)
@@ -105,3 +106,72 @@ def test_recorded_v5e_trace():
                     pytest.approx(1.96632e-4)]
     assert trace.collective_exposed(tr, lo, hi) == [0.0]
     assert set(trace.idle_by_span(tr, lo, hi)) == {"none"}
+
+
+def uncovered_by_definition(intervals, cover, lo, hi):
+    return sum((e - s) - trace.covered(cover, s, e)
+               for s, e in trace.merge(trace.clip(intervals, lo, hi)))
+
+
+def idle_by_span_by_definition(tr, lo, hi):
+    spans = sorted(tr["spans"], key=lambda x: x[2] - x[1])
+    out = {}
+    nd = max(1, len(tr["devices"]))
+    for d in tr["devices"].values():
+        for s, e in trace.gaps([(a, b) for _, a, b in d["ops"]], lo, hi):
+            mid = (s + e) / 2
+            name = next((n for n, a, b in spans
+                         if a <= mid <= b and n != "bench.window"), "none")
+            out[name] = out.get(name, 0.0) + (e - s) * 1e-9 / nd
+    return out
+
+
+def random_trace(rng):
+    devices = {}
+    for d in range(rng.randint(1, 4)):
+        ops = []
+        for _ in range(rng.randint(0, 60)):
+            s = rng.randint(0, 1000)
+            name = rng.choice(["fusion.1", "all-gather.2", "all-reduce-start",
+                               "convolution.3"])
+            ops.append((name, s, s + rng.randint(0, 80)))
+        devices[f"/device:TPU:{d}"] = {"ops": ops}
+    spans = [("bench.window", 100, 900)]
+    for k in range(rng.randint(0, 12)):
+        s = rng.randint(0, 1000)
+        spans.append((rng.choice(["bench.run_step", "bench.input", "x"]), s,
+                      s + rng.randint(0, 300)))
+    return {"devices": devices, "spans": spans}
+
+
+@pytest.mark.parametrize("seed", range(20))
+def test_sweeps_match_their_definitions(seed):
+    """``uncovered`` and ``idle_by_span`` (one pass over a trace's
+    millions of ops) read exactly what their definitions read."""
+    import random
+    tr = random_trace(random.Random(seed))
+    for d in tr["devices"].values():
+        coll = [(s, e) for n, s, e in d["ops"] if trace.COLLECTIVE.search(n)]
+        comp = [(s, e) for n, s, e in d["ops"]
+                if not trace.COLLECTIVE.search(n)]
+        assert trace.uncovered(coll, comp, 100, 900) == \
+            uncovered_by_definition(coll, comp, 100, 900)
+    assert trace.idle_by_span(tr, 100, 900) == \
+        idle_by_span_by_definition(tr, 100, 900)
+
+
+def test_loop_ops_cover_no_collective():
+    """A trace lists a loop op and the ops of its body: the loop covers
+    none of the body's collectives, and an op that reads a collective's
+    result is compute."""
+    ops = [("%while.3 = (f32[8]) while(%tuple.1), body=%b", 0, 100),
+           ("%fusion.1 = f32[8] fusion(%p)", 0, 30),
+           ("%all-reduce.4 = u32[8] all-reduce(%fusion.1)", 30, 60),
+           ("%fusion.2 = f32[8] fusion(%all-reduce.4)", 50, 70),
+           ("%all-gather-start.5 = f32[8] all-gather-start(%x)", 80, 90)]
+    tr = {"devices": {"/device:TPU:0": {"ops": ops}}, "spans": []}
+    assert [trace.op_kind(n) for n, _, _ in ops] == [
+        "while", "fusion", "all-reduce", "fusion", "all-gather-start"]
+    assert [trace.is_collective(n) for n, _, _ in ops] == [
+        False, False, True, False, True]
+    assert trace.collective_exposed(tr, 0, 100) == [pytest.approx(30e-9)]

@@ -12,7 +12,10 @@ the same way:
 * idle gaps: the window minus that union, each gap attributed to the
   innermost benchmark span that covers its midpoint;
 * exposed collective time: the part of the collective ops' intervals
-  that no compute op on the same device covers.
+  that no compute op on the same device covers.  A loop or call op
+  (``while``, ``conditional``, ``call``) spans the ops of its body, which
+  the trace lists as well, so it is neither: a collective inside a loop
+  is exposed unless a compute op of the body runs beside it.
 """
 from __future__ import annotations
 
@@ -29,6 +32,23 @@ PAGED_KERNEL = r'custom_call_target="tpu_custom_call"'
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all|"
     r"allreduce|allgather|reducescatter", re.I)
+CONTAINERS = ("while", "conditional", "call")
+_KIND = re.compile(r"[ )]([a-z][a-z0-9-]*)\(")
+
+
+def op_kind(op: str) -> str:
+    """'%fusion.12 = bf16[2,4096]{...} fusion(...), ...' -> 'fusion'; an
+    op given by its name alone is its own kind."""
+    if " = " not in op:
+        return op
+    kind = _KIND.search(op.split(" = ", 1)[1])
+    return kind.group(1) if kind else ""
+
+
+def is_collective(op: str) -> bool:
+    """By the op's name or kind, never by its operands' names."""
+    return bool(COLLECTIVE.search(op.split(" = ", 1)[0])
+                or COLLECTIVE.search(op_kind(op)))
 
 
 # ----------------------------------------------------------- interval math
@@ -68,10 +88,18 @@ def gaps(intervals, lo, hi) -> list:
 
 
 def uncovered(intervals, cover, lo, hi) -> float:
-    """Length of the union of ``intervals`` that ``cover`` leaves open."""
-    total = 0.0
+    """Length of the union of ``intervals`` that ``cover`` leaves open, in
+    one pass over both unions (a trace holds millions of ops)."""
+    cov = merge(clip(cover, lo, hi))
+    total, j = 0.0, 0
     for s, e in merge(clip(intervals, lo, hi)):
-        total += (e - s) - covered(cover, s, e)
+        total += e - s
+        while j < len(cov) and cov[j][1] <= s:
+            j += 1
+        k = j
+        while k < len(cov) and cov[k][0] < e:
+            total -= min(e, cov[k][1]) - max(s, cov[k][0])
+            k += 1
     return total
 
 
@@ -91,12 +119,17 @@ def device_busy(tr: dict, lo, hi) -> list:
 
 
 def collective_exposed(tr: dict, lo, hi) -> list:
-    """Per device, seconds of collective ops not covered by compute."""
-    out = []
+    """Per device, seconds of collective ops not covered by compute
+    (loop and call ops are neither)."""
+    out, role = [], {}          # op name -> "coll", "comp" or "loop"
     for d in tr["devices"].values():
-        coll = [(s, e) for n, s, e in d["ops"] if COLLECTIVE.search(n)]
-        comp = [(s, e) for n, s, e in d["ops"] if not COLLECTIVE.search(n)]
-        out.append(uncovered(coll, comp, lo, hi) * 1e-9)
+        ivs = {"coll": [], "comp": [], "loop": []}
+        for n, s, e in d["ops"]:
+            if n not in role:
+                role[n] = ("loop" if op_kind(n) in CONTAINERS else
+                           "coll" if is_collective(n) else "comp")
+            ivs[role[n]].append((s, e))
+        out.append(uncovered(ivs["coll"], ivs["comp"], lo, hi) * 1e-9)
     return out
 
 
@@ -118,16 +151,24 @@ def op_seconds(tr: dict, lo, hi, pattern=None) -> dict:
 def idle_by_span(tr: dict, lo, hi) -> dict:
     """Idle seconds of each device's gaps, averaged over devices, by the
     innermost benchmark span covering each gap's midpoint ('none' where
-    no span covers it)."""
-    spans = sorted(tr["spans"], key=lambda x: x[2] - x[1])
+    no span covers it).  Spans are taken shortest first, each naming the
+    gaps it covers that no shorter span has named."""
+    spans = sorted((x for x in tr["spans"] if x[0] != "bench.window"),
+                   key=lambda x: x[2] - x[1])
     out: dict = {}
     nd = max(1, len(tr["devices"]))
     for d in tr["devices"].values():
-        for s, e in gaps([(a, b) for _, a, b in d["ops"]], lo, hi):
-            mid = (s + e) / 2
-            name = next((n for n, a, b in spans
-                         if a <= mid <= b and n != "bench.window"), "none")
-            out[name] = out.get(name, 0.0) + (e - s) * 1e-9 / nd
+        idle = gaps([(a, b) for _, a, b in d["ops"]], lo, hi)
+        mids = [(s + e) / 2 for s, e in idle]     # sorted, as the gaps are
+        names = [None] * len(idle)
+        for n, a, b in spans:
+            for i in range(bisect.bisect_left(mids, a),
+                           bisect.bisect_right(mids, b)):
+                if names[i] is None:
+                    names[i] = n
+        for (s, e), n in zip(idle, names):
+            n = n or "none"
+            out[n] = out.get(n, 0.0) + (e - s) * 1e-9 / nd
     return out
 
 
@@ -165,7 +206,7 @@ def short_name(op: str) -> str:
     if " = " not in op:
         return op[:100]
     lhs, rhs = op.split(" = ", 1)
-    kind = re.search(r"[ )]([a-z][a-z0-9-]*)\(", rhs)
+    kind = _KIND.search(rhs)
     head = rhs[:kind.start()] if kind else rhs
     typ = re.search(r"[a-z0-9]+\[[0-9][0-9,]*\]", head)
     return " ".join(x for x in (lhs.lstrip("%"),

@@ -10,6 +10,10 @@ window starts at a step boundary, runs whole steps, and ends at the first
 step boundary after ``--seconds``, when that step's loss has been fetched.
 ``train_tokens_per_s`` is every token of every window step over the
 window's span.
+
+A traced run traces the window's first ``trace_steps(chips)`` whole steps
+(all of them, where the window holds fewer) and reads its per-layer
+metrics over those; the window itself runs as in an untraced run.
 """
 from __future__ import annotations
 
@@ -22,24 +26,28 @@ import numpy as np
 from . import common, reference
 
 N_CHECK = 3           # steps the reference follows
+# Steps times chips that a traced run captures at most.  The capture, and
+# the time to write it out and read it back, grow with both; the one-chip
+# cell's whole window (~130 steps) fits a run's time with room, and this
+# keeps it whole while four chips capture 48 steps.
+TRACE_DEVICE_STEPS = 192
 
 
-def build_session(dims, model_cfg: dict, traffic: dict, chips: int,
+def trace_steps(chips: int) -> int:
+    """Whole window steps a traced run on ``chips`` chips captures."""
+    return max(1, TRACE_DEVICE_STEPS // chips)
+
+
+def build_session(fam, dims, model_cfg: dict, traffic: dict, chips: int,
                   seed: int):
     """A TrainSession for this cell, on the first ``chips`` devices."""
     from repro.api.session import TrainSession
     from repro.api.spec import MeshSpec, RunSpec
     from repro.collectives import SyncConfig
     from repro.data.pipeline import DataConfig
-    from repro.models.config import ModelConfig
     from repro.optim import AdamWConfig
 
-    mcfg = ModelConfig(
-        name=model_cfg["name"], family="dense", n_layers=dims.n_layers,
-        d_model=dims.d_model, n_heads=dims.n_heads,
-        n_kv_heads=dims.n_kv_heads, d_ff=dims.d_ff, vocab=dims.vocab,
-        head_dim=dims.head_dim, rope_theta=dims.rope_theta,
-        dtype=model_cfg["dtype"])
+    mcfg = fam.program_config(dims, model_cfg)
 
     class CellSpec(RunSpec):
         def model_config(self):
@@ -80,13 +88,14 @@ def run(cell: dict, model_cfg: dict, traffic: dict, seed: int,
         seconds: float, devs, counter, tracer=None) -> tuple:
     import jax
 
-    dims = reference.Dims.from_config(model_cfg)
+    fam = common.family(model_cfg)
+    dims = fam.Dims.from_config(model_cfg)
     opt = reference.AdamW.from_config(model_cfg["optimizer"])
     chips = cell["chips"]
     seed = seed % 2 ** 31
     excluded = 0.0            # set-up seconds spent only for the check
 
-    session = build_session(dims, model_cfg, traffic, chips, seed)
+    session = build_session(fam, dims, model_cfg, traffic, chips, seed)
     tokens_per_step = traffic["batch_per_chip"] * chips * traffic["seq_len"]
     with jax.set_mesh(session.mesh):
         t = time.perf_counter()
@@ -112,8 +121,12 @@ def run(cell: dict, model_cfg: dict, traffic: dict, seed: int,
             tracer.wrap_train(session)
         setup_s = common.process_age_s() - excluded
         counter.armed = True
+        traced = trace_steps(chips) if tracer is not None else 0
+        trace_end = contextlib.ExitStack()
         if tracer is not None:
             tracer.start()
+            trace_end.callback(tracer.stop)
+            trace_end.enter_context(tracer.span("bench.window"))
         first = step
         ends = [time.perf_counter()]
         stalls = StallLog(session.data)
@@ -122,10 +135,11 @@ def run(cell: dict, model_cfg: dict, traffic: dict, seed: int,
             with _span(tracer, "bench.run_step"):
                 session.run_step(first + k)
             ends.append(time.perf_counter())
-        with _span(tracer, "bench.window"), stalls:
+            if k + 1 == traced:
+                trace_end.close()
+        with stalls:
             n, window = whole_step_window(one, seconds)
-        if tracer is not None:
-            tracer.stop()
+        trace_end.close()
         counter.armed = False
     device = common.device_info(devs)
 
@@ -137,12 +151,13 @@ def run(cell: dict, model_cfg: dict, traffic: dict, seed: int,
         a.delete()
 
     readings = {
-        "steps": n, "window_s": window, "chips": chips,
+        "steps": min(n, traced) if tracer is not None else n,
+        "window_s": window, "chips": chips,
         "tokens_per_step": tokens_per_step, "seq_len": traffic["seq_len"],
-        "dims": dims, "device_kind": device["kind"],
+        "family": fam, "dims": dims, "device_kind": device["kind"],
     }
     t = time.perf_counter()
-    gaps = check(dims, opt, traffic, chips, seed, losses, g1, p0, p3)
+    gaps = check(fam, dims, opt, traffic, chips, seed, losses, g1, p0, p3)
     checks = compare(gaps, traffic["limits"])
     check_s = time.perf_counter() - t
     checks["window_compiles"] = common.check(counter.count, 0)
@@ -174,16 +189,16 @@ def batches(dims, traffic, chips, seed) -> list:
             for s in range(N_CHECK)]
 
 
-def ref_readings(dims, opt, rows, seed, ar=reference.F32, chips=1,
+def ref_readings(fam, dims, opt, rows, seed, ar=reference.F32, chips=1,
                  ex=reference.MEAN) -> tuple:
     """(losses, first clipped gradient's leaf norms, leaf norms of the
     weight change) of the reference over the token ``rows`` of each
     checked step, split over ``chips`` and made one by ``ex``, from the
     seed's weights."""
     import jax
-    out = reference.train(dims, opt, reference.init_weights(dims, seed),
+    out = reference.train(fam.loss, dims, opt, fam.init_weights(dims, seed),
                           rows, ar, chips, ex)
-    w0 = reference.init_weights(dims, seed)
+    w0 = fam.init_weights(dims, seed)
     d = np.asarray(reference.leaf_norms(jax.tree.map(
         lambda a, b: a.astype("float32") - b.astype("float32"),
         out.pop("weights"), w0)))
@@ -192,11 +207,12 @@ def ref_readings(dims, opt, rows, seed, ar=reference.F32, chips=1,
     return out["losses"], out["g1_norms"], d
 
 
-def check(dims, opt, traffic, chips, seed, losses, g1, p0, p3) -> dict:
+def check(fam, dims, opt, traffic, chips, seed, losses, g1, p0,
+          p3) -> dict:
     """The reference follows the checked steps on the same seed; the
     program's numbers are read against it leaf by leaf (``gaps``)."""
-    ref = ref_readings(dims, opt, batches(dims, traffic, chips, seed), seed,
-                       chips=chips, ex=exchange_of(traffic))
+    ref = ref_readings(fam, dims, opt, batches(dims, traffic, chips, seed),
+                       seed, chips=chips, ex=exchange_of(traffic))
     d_prog = _host_delta_norms(p0, p3)
     return gaps_of(losses, g1, d_prog, *ref)
 
